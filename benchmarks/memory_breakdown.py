@@ -17,6 +17,7 @@ import jax
 
 from repro.configs.base import ArchConfig
 from repro.core import paper_recipe
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.models.model_api import train_batch_specs
 from repro.configs.base import ShapeConfig
@@ -63,6 +64,7 @@ def measure(cfg: ArchConfig, batch: int, seq: int = 1024) -> dict:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", default="2,4,8")
     ap.add_argument("--seq", type=int, default=1024)

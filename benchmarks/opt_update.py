@@ -47,6 +47,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.core import qadam
 from repro.core.qconfig import parse_recipe
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.optim import (OptConfig, adamw_update, init_adam_state,
                          opt_path_desc)
@@ -168,6 +169,7 @@ def smoke() -> None:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--json", default="",

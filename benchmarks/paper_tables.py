@@ -26,6 +26,7 @@ from repro.configs import get_smoke_config
 from repro.core.qconfig import Granularity as G
 from repro.core.qconfig import QuantRecipe, QuantSpec
 from repro.data import Loader, SyntheticCorpus
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.optim import OptConfig
 from repro.train import init_train_state, make_eval_step, make_train_step
@@ -211,6 +212,7 @@ def check_claims(results: dict) -> list:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

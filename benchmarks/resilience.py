@@ -54,6 +54,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.launch.compile_cache import setup_compile_cache
+
 KEY = jax.random.PRNGKey(0)
 
 
@@ -418,6 +420,7 @@ def run_all(out_path: str = "BENCH_resilience.json", smoke: bool = False,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="assert every gate; write BENCH_resilience.json")

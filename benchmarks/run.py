@@ -28,6 +28,7 @@ from repro.core.qconfig import Granularity, QuantRecipe, QuantSpec
 from repro.core.quantizer import fake_quant_nograd
 from repro.core.qlinear import quantized_linear
 from repro.data import Loader, SyntheticCorpus
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.optim import OptConfig
 from repro.train import init_train_state, make_train_step
@@ -271,6 +272,7 @@ def table_roofline() -> None:
 
 
 def main() -> None:
+    setup_compile_cache()
     print("name,us_per_call,derived")
     bench_quantizer_ops()
     bench_kernels()

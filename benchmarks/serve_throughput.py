@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.infer import Engine, Request, params_nbytes
+from repro.launch.compile_cache import setup_compile_cache
 
 POLICIES = ("*=fp", "*=w8c", "*=w8c+a8t", "kv_cache=a8t,*=w8c")
 
@@ -457,6 +458,7 @@ def bench_aot_smoke(*, slots: int = 4, max_seq: int = 64,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny engine pass + sanity assertions (CI gate)")

@@ -34,6 +34,7 @@ from repro.configs import get_smoke_config
 from repro.core import as_policy
 from repro.core.qlinear import _qlinear_fwd, _qlinear_int8_fwd
 from repro.data import Loader, SyntheticCorpus
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.optim import OptConfig
 from repro.train import init_train_state, make_train_step
@@ -130,6 +131,7 @@ def smoke() -> None:
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=3)
     ap.add_argument("--batch", type=int, default=8)

@@ -9,20 +9,22 @@ storage win exactly where it matters most (Jorgensen 2025; Bondarenko et al.
 2021 make the same point for kernels generally: the low-bit payload must be
 consumed *in-kernel*).
 
-This kernel runs one decode step per (slot, kv-head) grid cell:
+This kernel runs one decode step per (slot, kv tile) grid cell, over every
+kv head of the tile:
 
-  stream in   int8 K/V payload tiles + fp32 scale sidecars (BlockSpec DMA),
-              the (G, hd) query tile of the head group, and the step's fresh
-              fp K/V rows
-  in-register dequantize by folding the per-position scales into the online-
-              softmax scores (K) and probabilities (V) -- rank-1 multiplies,
-              no fp K/V tile ever materializes; scale==0 padding rows are
-              guarded like the NT/TN matmul kernels
-  fused       quantize the new K/V row (symmetric nearest per-(position,
+  stream in   int8 K/V payload tiles spanning all kv heads + fp32 scale
+              sidecars (BlockSpec DMA; the blocks' last two dims are the
+              array's own (K, hd) / (K, 1), the TPU tiling rule), the
+              (K, G, hd) query tile, and the step's fresh fp K/V rows
+  in VMEM     dequantize each head's (bk, hd) tile against its scale column
+              and fold it into that head's online softmax; only int8 crosses
+              HBM.  scale==0 padding rows are guarded like the NT/TN matmul
+              kernels
+  fused       quantize the new K/V rows (symmetric nearest per-(position,
               head), the `_kv_quant` codec) and scatter payload + scales into
               the cache row ``pos[b]`` via scalar-prefetch-indexed output
               blocks aliased onto the cache buffers
-  write out   the (G, hd) context tile and ONE int8 row + scale pair per
+  write out   the (K, G, hd) context tile and ONE int8 row + scale row per
               K/V buffer -- one read of the int8 cache, one int8 row write.
 
 Per-slot ``pos`` (B,) drives both the validity mask (cache rows < pos[b])
@@ -45,8 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attn import online_softmax_update
-from repro.kernels.pallas_compat import CompilerParams
 
 DEFAULT_BLOCK_K = 256
 
@@ -125,8 +127,9 @@ def _decode_attn_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                         bk: int, nblk: int, scale: float,
                         qmin: int, qmax: int):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
     pos = pos_ref[b]
+    kh = q_ref.shape[1]
 
     @pl.when(ki == 0)
     def _init():
@@ -137,48 +140,57 @@ def _decode_attn_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
     # tiles entirely past the slot's valid rows contribute nothing: skip
     @pl.when(ki * bk < pos)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, hd)
-        kt = kq_ref[0, :, 0, :].astype(jnp.float32)            # (bk, hd)
-        ksc = _guard(ks_ref[0, :, 0, :].astype(jnp.float32))   # (bk, 1)
-        s = jax.lax.dot_general(q, kt, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * ksc[:, 0][None, :]          # fold K dequant into the scores
         t = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(t < pos, s, -1e30)    # prior rows only; new row below
-        vsc = _guard(vs_ref[0, :, 0, :].astype(jnp.float32))   # (bk, 1)
-        online_softmax_update(s, vq_ref[0, :, 0, :].astype(jnp.float32),
-                              m_ref, l_ref, acc_ref,
-                              v_fold=vsc[:, 0][None, :])
+        for h in range(kh):          # every kv head of the one DMA'd tile
+            q = q_ref[0, h].astype(jnp.float32) * scale            # (G, hd)
+            kt = (kq_ref[0, :, h, :].astype(jnp.float32)
+                  * _guard(ks_ref[0, :, h, :]))                    # (bk, hd)
+            s = jax.lax.dot_general(q, kt, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(t < pos, s, -1e30)  # prior rows; new row below
+            vt = (vq_ref[0, :, h, :].astype(jnp.float32)
+                  * _guard(vs_ref[0, :, h, :]))
+            online_softmax_update(s, vt, m_ref.at[h], l_ref.at[h],
+                                  acc_ref.at[h])
 
     @pl.when(ki == nblk - 1)
     def _done():
-        # fused quantize + scatter of the step's K/V row: the attention reads
-        # the freshly *quantized* values (parity with the stored form), and
-        # the int8 payload + scale land in the cache row ``pos[b]`` through
-        # the scalar-prefetch-indexed, cache-aliased output blocks.
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, hd)
-        knew = nk_ref[0, 0].astype(jnp.float32).reshape(1, -1)  # (1, hd)
-        vnew = nv_ref[0, 0].astype(jnp.float32).reshape(1, -1)
+        # fused quantize + scatter of the step's K/V rows (all heads): the
+        # attention reads the freshly *quantized* values (parity with the
+        # stored form), and the int8 payload + scales land in the cache row
+        # ``pos[b]`` through the scalar-prefetch-indexed, cache-aliased
+        # output blocks.
+        knew = nk_ref[0].astype(jnp.float32)                   # (K, hd)
+        vnew = nv_ref[0].astype(jnp.float32)
         ks_new = jnp.maximum(jnp.max(jnp.abs(knew), axis=-1, keepdims=True),
-                             _EPS) / qmax
+                             _EPS) / qmax                      # (K, 1)
         kq_new = jnp.clip(jnp.round(knew / ks_new), qmin, qmax)
         vs_new = jnp.maximum(jnp.max(jnp.abs(vnew), axis=-1, keepdims=True),
                              _EPS) / qmax
         vq_new = jnp.clip(jnp.round(vnew / vs_new), qmin, qmax)
-        s_new = jax.lax.dot_general(q, kq_new * ks_new,
-                                    (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        okq_ref[0, 0] = kq_new.astype(okq_ref.dtype)
+        oks_ref[0, 0] = ks_new
+        ovq_ref[0, 0] = vq_new.astype(ovq_ref.dtype)
+        ovs_ref[0, 0] = vs_new
+        # attend on the rows as stored, all heads at once: (K, G, hd) q
+        # against the (K, 1, hd) new K/V rows
+        kdeq = (okq_ref[0, 0].astype(jnp.float32) * oks_ref[0, 0])[:, None]
+        vdeq = (ovq_ref[0, 0].astype(jnp.float32) * ovs_ref[0, 0])[:, None]
+        q = q_ref[0].astype(jnp.float32) * scale               # (K, G, hd)
+        s_new = jnp.sum(q * kdeq, axis=-1, keepdims=True)      # (K, G, 1)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s_new)                     # (G, 1)
+        m_new = jnp.maximum(m_prev, s_new)
         alpha = jnp.exp(m_prev - m_new)
         p_new = jnp.exp(s_new - m_new)
         l = alpha * l_ref[...] + p_new
-        acc = acc_ref[...] * alpha + p_new * (vq_new * vs_new)
-        o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        okq_ref[0, 0, 0] = kq_new[0].astype(okq_ref.dtype)
-        oks_ref[0, 0, 0, 0] = ks_new[0, 0]
-        ovq_ref[0, 0, 0] = vq_new[0].astype(ovq_ref.dtype)
-        ovs_ref[0, 0, 0, 0] = vs_new[0, 0]
+        acc = acc_ref[...] * alpha + p_new * vdeq
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _decode_scratch(kh: int, g: int, hd: int):
+    return [pltpu.VMEM((kh, g, 1), jnp.float32),      # running max
+            pltpu.VMEM((kh, g, 1), jnp.float32),      # running sum
+            pltpu.VMEM((kh, g, hd), jnp.float32)]     # accumulator
 
 
 def decode_attention(q: jnp.ndarray,
@@ -199,9 +211,12 @@ def decode_attention(q: jnp.ndarray,
     lengths == scatter rows.  Returns ``(ctx, kq', ks', vq', vs')`` where the
     primed buffers are the caches with the new row written (aliased in
     place: the inputs are donated).
+
+    Grid ``(slots, kv_tiles)``: each block spans every kv head, so its last
+    two dims are the array's own ``(K, hd)`` / ``(K, 1)`` -- the TPU tiling
+    rule -- and each cache tile is DMA'd once for all heads.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b, kh, g, hd = q.shape
     q, g, g_real = _lane_align_q(q)
     s = kq.shape[1]
@@ -218,34 +233,28 @@ def decode_attention(q: jnp.ndarray,
         # and the slot's output is discarded by the scheduler.
         return jnp.minimum(pos_ref[bi], s - 1)
 
+    tile = lambda b, j, pos_ref: (b, j, 0, 0)
+    at_row = lambda b, j, pos_ref: (b, row(pos_ref, b), 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kh, nblk),
+        grid=(b, nblk),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, k, j, pos_ref: (b, k, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, k, j, pos_ref: (b, j, k, 0)),
-            pl.BlockSpec((1, bk, 1, 1), lambda b, k, j, pos_ref: (b, j, k, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, k, j, pos_ref: (b, j, k, 0)),
-            pl.BlockSpec((1, bk, 1, 1), lambda b, k, j, pos_ref: (b, j, k, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, k, j, pos_ref: (b, k, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, k, j, pos_ref: (b, k, 0)),
+            pl.BlockSpec((1, kh, g, hd), lambda b, j, pos_ref: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bk, kh, hd), tile),
+            pl.BlockSpec((1, bk, kh, 1), tile),
+            pl.BlockSpec((1, bk, kh, hd), tile),
+            pl.BlockSpec((1, bk, kh, 1), tile),
+            pl.BlockSpec((1, kh, hd), lambda b, j, pos_ref: (b, 0, 0)),
+            pl.BlockSpec((1, kh, hd), lambda b, j, pos_ref: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, k, j, pos_ref: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, k, j, pos_ref: (b, row(pos_ref, b), k, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, k, j, pos_ref: (b, row(pos_ref, b), k, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, k, j, pos_ref: (b, row(pos_ref, b), k, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, k, j, pos_ref: (b, row(pos_ref, b), k, 0)),
+            pl.BlockSpec((1, kh, g, hd), lambda b, j, pos_ref: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, kh, hd), at_row),
+            pl.BlockSpec((1, 1, kh, 1), at_row),
+            pl.BlockSpec((1, 1, kh, hd), at_row),
+            pl.BlockSpec((1, 1, kh, 1), at_row),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),      # running max
-            pltpu.VMEM((g, 1), jnp.float32),      # running sum
-            pltpu.VMEM((g, hd), jnp.float32),     # accumulator
-        ],
+        scratch_shapes=_decode_scratch(kh, g, hd),
     )
     ctx, okq, oks, ovq, ovs = pl.pallas_call(
         functools.partial(_decode_attn_kernel, bk=bk, nblk=nblk, scale=scale,
@@ -261,8 +270,8 @@ def decode_attention(q: jnp.ndarray,
         # the int8 caches and scale sidecars update in place: only the
         # pos[b] row blocks are DMA'd back
         input_output_aliases={2: 1, 3: 2, 4: 3, 5: 4},
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, q, kq, ks, vq, vs, new_k, new_v)
     return _lane_trim_ctx(ctx, g_real), okq, oks, ovq, ovs
@@ -296,8 +305,8 @@ def decode_attention_paged(q: jnp.ndarray,
     slot's logical page j to a physical pool page (unmapped entries point at
     the trash page 0).
 
-    The grid is the dense kernel's ``(slots, kv_heads, kv_tiles)`` with the
-    kv tile pinned to one page: both ``pos`` and the page table are scalar-
+    The grid is the dense kernel's ``(slots, kv_tiles)`` with the kv tile
+    pinned to one page: both ``pos`` and the page table are scalar-
     prefetched, and the *input* index maps route logical tile ``j`` to
     physical page ``page_table[b, min(j, ceil(pos[b]/page)-1)]`` -- tiles
     past the slot's live length are clamped to the last live page, so no
@@ -306,70 +315,47 @@ def decode_attention_paged(q: jnp.ndarray,
     new-row scatter targets ``(page_table[b, pos[b]//page], pos[b]%page)``.
     Returns ``(ctx, kq', ks', vq', vs')`` with the pools aliased in place.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     b, kh, g, hd = q.shape
     q, g, g_real = _lane_align_q(q)
-    npages, page = kq.shape[0], kq.shape[1]
+    page = kq.shape[1]
     maxp = page_table.shape[1]
     scale = 1.0 / math.sqrt(hd)
     last = maxp * page - 1
 
-    def rd(bi, j, pos_ref, pt_ref):
-        live_last = jnp.maximum((pos_ref[bi] + page - 1) // page - 1, 0)
-        return pt_ref[bi, jnp.minimum(j, live_last)]
+    def rd(b, j, pos_ref, pt_ref):
+        live_last = jnp.maximum((pos_ref[b] + page - 1) // page - 1, 0)
+        return pt_ref[b, jnp.minimum(j, live_last)], 0, 0, 0
 
-    def wr(bi, pos_ref, pt_ref):
+    def wr(b, j, pos_ref, pt_ref):
         # clamp like the dense kernel: pos == maxp*page is the degenerate
         # freed-slot case; the row lands in the slot's last mapped page and
         # is never read back
-        p = jnp.minimum(pos_ref[bi], last)
-        return pt_ref[bi, p // page], p % page
+        p = jnp.minimum(pos_ref[b], last)
+        return pt_ref[b, p // page], p % page, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kh, maxp),
+        grid=(b, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda b, k, j, pos_ref, pt_ref: (b, k, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         (rd(b, j, pos_ref, pt_ref), 0, k, 0)),
-            pl.BlockSpec((1, page, 1, 1),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         (rd(b, j, pos_ref, pt_ref), 0, k, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         (rd(b, j, pos_ref, pt_ref), 0, k, 0)),
-            pl.BlockSpec((1, page, 1, 1),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         (rd(b, j, pos_ref, pt_ref), 0, k, 0)),
-            pl.BlockSpec((1, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref: (b, k, 0)),
-            pl.BlockSpec((1, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref: (b, k, 0)),
+            pl.BlockSpec((1, kh, g, hd),
+                         lambda b, j, pos_ref, pt_ref: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, kh, hd), rd),
+            pl.BlockSpec((1, page, kh, 1), rd),
+            pl.BlockSpec((1, page, kh, hd), rd),
+            pl.BlockSpec((1, page, kh, 1), rd),
+            pl.BlockSpec((1, kh, hd), lambda b, j, pos_ref, pt_ref: (b, 0, 0)),
+            pl.BlockSpec((1, kh, hd), lambda b, j, pos_ref, pt_ref: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g, hd),
-                         lambda b, k, j, pos_ref, pt_ref: (b, k, 0, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         wr(b, pos_ref, pt_ref) + (k, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         wr(b, pos_ref, pt_ref) + (k, 0)),
-            pl.BlockSpec((1, 1, 1, hd),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         wr(b, pos_ref, pt_ref) + (k, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda b, k, j, pos_ref, pt_ref:
-                         wr(b, pos_ref, pt_ref) + (k, 0)),
+            pl.BlockSpec((1, kh, g, hd),
+                         lambda b, j, pos_ref, pt_ref: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, kh, hd), wr),
+            pl.BlockSpec((1, 1, kh, 1), wr),
+            pl.BlockSpec((1, 1, kh, hd), wr),
+            pl.BlockSpec((1, 1, kh, 1), wr),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),      # running max
-            pltpu.VMEM((g, 1), jnp.float32),      # running sum
-            pltpu.VMEM((g, hd), jnp.float32),     # accumulator
-        ],
+        scratch_shapes=_decode_scratch(kh, g, hd),
     )
     ctx, okq, oks, ovq, ovs = pl.pallas_call(
         functools.partial(_paged_decode_attn_kernel, bk=page, nblk=maxp,
@@ -385,8 +371,8 @@ def decode_attention_paged(q: jnp.ndarray,
         # pools alias in place (operands 0/1 are the prefetched scalars):
         # only the one written row block per slot is DMA'd back
         input_output_aliases={3: 1, 4: 2, 5: 3, 6: 4},
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(pos, page_table, q, kq, ks, vq, vs, new_k, new_v)
     return _lane_trim_ctx(ctx, g_real), okq, oks, ovq, ovs
@@ -423,7 +409,6 @@ def decode_attention_spmd(q, kq, ks, vq, vs, new_k, new_v, pos, *,
     BlockSpec DMA never crosses chips).  Math is bitwise identical to the
     single-device kernel: per-(slot, head) online softmax has no cross-shard
     reduction.  ``pos`` is replicated (host-side slot bookkeeping)."""
-    from repro.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
     kv4 = P(None, None, kv_axis, None)
 
@@ -432,11 +417,12 @@ def decode_attention_spmd(q, kq, ks, vq, vs, new_k, new_v, pos, *,
                                 qmin=qmin, qmax=qmax, block_k=block_k,
                                 interpret=interpret)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None, kv_axis, None, None), kv4, kv4, kv4, kv4,
                   P(None, kv_axis, None), P(None, kv_axis, None), P()),
         out_specs=(P(None, kv_axis, None, None), kv4, kv4, kv4, kv4),
+        check_vma=False,
     )(q, kq, ks, vq, vs, new_k, new_v, pos)
 
 
@@ -449,7 +435,6 @@ def decode_attention_paged_spmd(q, kq, ks, vq, vs, new_k, new_v, pos,
     their kv-head dim (``PAGED_POOL_AXES``), the page table and ``pos`` are
     replicated scalar bookkeeping, and every shard DMAs pages of its local
     head slice only."""
-    from repro.parallel.compat import shard_map
     from jax.sharding import PartitionSpec as P
     pool4 = P(None, None, kv_axis, None)
 
@@ -458,11 +443,12 @@ def decode_attention_paged_spmd(q, kq, ks, vq, vs, new_k, new_v, pos,
                                       pos_, pt_, qmin=qmin, qmax=qmax,
                                       interpret=interpret)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(None, kv_axis, None, None), pool4, pool4, pool4, pool4,
                   P(None, kv_axis, None), P(None, kv_axis, None), P(), P()),
         out_specs=(P(None, kv_axis, None, None), pool4, pool4, pool4, pool4),
+        check_vma=False,
     )(q, kq, ks, vq, vs, new_k, new_v, pos, page_table)
 
 

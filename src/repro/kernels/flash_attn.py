@@ -27,28 +27,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.int8_matmul import scale_guard
-from repro.kernels.pallas_compat import CompilerParams
 
 BLOCK_Q = 512
 BLOCK_K = 512
 
 
-def online_softmax_update(s, vt, m_ref, l_ref, acc_ref, v_fold=None):
+def online_softmax_update(s, vt, m_ref, l_ref, acc_ref):
     """One online-softmax accumulation step: fold the scores tile ``s`` into
     the running (m, l, acc) scratch against the value tile ``vt``.  Shared
     by every forward kernel (fp, LSE-emitting, int8-dequant-prologue, and
-    the decode kernel in decode_attn.py) so the recurrence exists once.
-    ``v_fold`` multiplies the probabilities by a rank-1 factor -- the V
-    dequant scale fold of the quantized variants."""
+    the decode kernel in decode_attn.py) so the recurrence exists once."""
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[...] = m_new
-    if v_fold is not None:
-        p = p * v_fold
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p.astype(vt.dtype), vt, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -100,8 +96,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     Sq % block_q == 0 and Skv % block_k == 0 (callers pad or shrink blocks).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bh, sq, d = q.shape
     skv = k.shape[1]
     bq = min(block_q, sq)
@@ -129,7 +124,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),     # running sum
             pltpu.VMEM((bq, d), jnp.float32),     # accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -168,7 +163,7 @@ def _flash_fwd_lse_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = m_ref[...] + jnp.log(l)
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -197,7 +192,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kpos = ki * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
             s = jnp.where(kpos <= qpos, s, -1e30)
-        p = jnp.exp(s - lse_ref[0][:, None])               # (bq, bk)
+        p = jnp.exp(s - lse_ref[0])                        # (bq, bk)
         do = do_ref[0].astype(jnp.float32)
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -205,7 +200,7 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -241,12 +236,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kpos = ki * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
             s = jnp.where(kpos <= qpos, s, -1e30)
-        p = jnp.exp(s - lse_ref[0][:, None])
+        p = jnp.exp(s - lse_ref[0])
         do = do_ref[0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v_ref[0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         dq_acc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -283,14 +278,14 @@ def _fwd_with_lse(q, k, v, causal, q_offset, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -302,8 +297,7 @@ def flash_attention(q, k, v, causal: bool = True, q_offset: int = 0,
                     interpret: Optional[bool] = None):
     """Differentiable flash attention: Pallas forward AND backward (the
     classic dKdV / dQ two-kernel recompute scheme with saved LSE rows)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     o, _ = _fwd_with_lse(q, k, v, causal, q_offset, block_q, block_k,
                          interpret)
     return o
@@ -322,24 +316,24 @@ def _ref_attend(q, k, v, causal, q_offset):
 
 
 def _fa_fwd(q, k, v, causal, q_offset, block_q, block_k, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     o, lse = _fwd_with_lse(q, k, v, causal, q_offset, block_q, block_k,
                            interpret)
     return o, (q, k, v, o, lse)
 
 
 def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     q, k, v, o, lse = res
     bh, sq, d = q.shape
     skv = k.shape[1]
     bq, bk = _blocks(sq, skv, block_q, block_k)
     nq, nk = sq // bq, skv // bk
     scale = 1.0 / math.sqrt(d)
+    # (bh, sq, 1): row statistics ride as (bq, 1) column blocks, the
+    # lane-legal layout the score tiles broadcast against
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                     # (bh, sq)
+                    axis=-1, keepdims=True)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, scale=scale, causal=causal,
@@ -350,8 +344,8 @@ def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # k
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),   # v
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),         # lse
-            pl.BlockSpec((1, bq), lambda b, j, i: (b, i)),         # delta
+            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),   # lse
+            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),   # delta
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
@@ -361,7 +355,7 @@ def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
                    jax.ShapeDtypeStruct((bh, skv, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, g.astype(q.dtype), lse, delta)
@@ -375,13 +369,13 @@ def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # k
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),   # v
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),         # lse
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),         # delta
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # lse
+            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),   # delta
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, g.astype(q.dtype), lse, delta)
@@ -397,15 +391,18 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # decode cache's stored form -- (B, Skv, K, hd) int8 payloads + (B, Skv, K, 1)
 # fp32 per-(position, head) scale sidecars -- directly, so int8-KV prefill
 # stops materializing a full fp K/V copy of the (max_seq-sized) cache buffer.
-# GQA rides on the index maps (kv block h // g), no head repeat.  Forward-only
-# (serving path); scale==0 padding rows are guarded (see decode_attn._guard).
+# GQA: q head h reads kv head h // g of the same tile, no head repeat.
+# Forward-only (serving path); scale==0 padding rows are guarded (see
+# decode_attn._guard).
 # ---------------------------------------------------------------------------
 
 def _flash_fwd_q8_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
                          bq: int, bk: int, nk: int, q_offset: int):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    h, kh = q_ref.shape[2], kq_ref.shape[2]
+    g = h // kh
 
     @pl.when(ki == 0)
     def _init():
@@ -417,27 +414,29 @@ def _flash_fwd_q8_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale       # (bq, d)
-        kt = kq_ref[0, :, 0, :].astype(jnp.float32)             # (bk, d)
-        ksc = scale_guard(ks_ref[0, :, 0, :].astype(jnp.float32))  # (bk, 1)
-        s = jax.lax.dot_general(q, kt, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * ksc[:, 0][None, :]        # fold the K dequant into the scores
         if causal:
             qpos = q_offset + qi * bq + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 0)
             kpos = ki * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, bk), 1)
-            s = jnp.where(kpos <= qpos, s, -1e30)
-        vsc = scale_guard(vs_ref[0, :, 0, :].astype(jnp.float32))
-        online_softmax_update(s, vq_ref[0, :, 0, :].astype(jnp.float32),
-                              m_ref, l_ref, acc_ref,
-                              v_fold=vsc[:, 0][None, :])
+        for j in range(kh):     # each kv tile dequantized once per kv head
+            kt = (kq_ref[0, :, j, :].astype(jnp.float32)
+                  * scale_guard(ks_ref[0, :, j, :]))            # (bk, d)
+            vt = (vq_ref[0, :, j, :].astype(jnp.float32)
+                  * scale_guard(vs_ref[0, :, j, :]))
+            for hh in range(j * g, (j + 1) * g):        # its GQA q heads
+                q = q_ref[0, :, hh, :].astype(jnp.float32) * scale  # (bq, d)
+                s = jax.lax.dot_general(q, kt, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                if causal:
+                    s = jnp.where(kpos <= qpos, s, -1e30)
+                online_softmax_update(s, vt, m_ref.at[hh], l_ref.at[hh],
+                                      acc_ref.at[hh])
 
     @pl.when(ki == nk - 1)
     def _done():
-        o_ref[0, :, 0, :] = (acc_ref[...] /
-                             jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        for hh in range(h):
+            o_ref[0, :, hh, :] = acc_ref[hh] / jnp.maximum(l_ref[hh], 1e-30)
 
 
 def flash_attention_fwd_q8(q: jnp.ndarray,
@@ -448,9 +447,12 @@ def flash_attention_fwd_q8(q: jnp.ndarray,
                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, Sq, H, hd); kq/vq: (B, Skv, K, hd) int8; ks/vs: (B, Skv, K, 1)
     fp32 -> (B, Sq, H, hd).  H % K == 0 (GQA/MQA); causal masking makes any
-    never-written cache tail (rows >= q_offset + Sq) invisible."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    never-written cache tail (rows >= q_offset + Sq) invisible.
+
+    Grid ``(B, Sq/bq, Skv/bk)``: every block spans all heads, so its last two
+    dims are the array's own ``(H|K, hd)`` -- the TPU tiling rule -- and each
+    int8 kv tile is DMA'd once for all the q heads that read it."""
+    interpret = resolve_interpret(interpret)
     b, sq, h, hd = q.shape
     skv, kh = kq.shape[1], kq.shape[2]
     assert h % kh == 0, (h, kh)
@@ -461,36 +463,28 @@ def flash_attention_fwd_q8(q: jnp.ndarray,
         raise ValueError(
             f"causal=False requires a fully written cache: Skv={skv} vs "
             f"q_offset+Sq={q_offset + sq}")
-    g = h // kh
     bq, bk = _blocks(sq, skv, block_q, block_k)
     nq, nk = sq // bq, skv // bk
     scale = 1.0 / math.sqrt(hd)
+    kv_tile = pl.BlockSpec((1, bk, kh, hd), lambda b, i, j: (b, j, 0, 0))
+    kv_scale = pl.BlockSpec((1, bk, kh, 1), lambda b, i, j: (b, j, 0, 0))
+    q_tile = pl.BlockSpec((1, bq, h, hd), lambda b, i, j: (b, i, 0, 0))
     return pl.pallas_call(
         functools.partial(_flash_fwd_q8_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, q_offset=q_offset),
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, hh, i, j: (b, i, hh, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, hh, i, j: (b, j, hh // g, 0)),
-            pl.BlockSpec((1, bk, 1, 1),
-                         lambda b, hh, i, j: (b, j, hh // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, hh, i, j: (b, j, hh // g, 0)),
-            pl.BlockSpec((1, bk, 1, 1),
-                         lambda b, hh, i, j: (b, j, hh // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, hh, i, j: (b, i, hh, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        grid=(b, nq, nk),
+        in_specs=[q_tile, kv_tile, kv_scale, kv_tile, kv_scale],
+        out_specs=q_tile,
+        # f32 out: Mosaic stores one head's (bq, hd) rows into the strided
+        # (bq, H, hd) tile for 32-bit types only
+        out_shape=jax.ShapeDtypeStruct((b, sq, h, hd), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((h, bq, 1), jnp.float32),
+                        pltpu.VMEM((h, bq, 1), jnp.float32),
+                        pltpu.VMEM((h, bq, hd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, kq, ks, vq, vs)
+    )(q, kq, ks, vq, vs).astype(q.dtype)
 
 
 def hbm_traffic_bytes(bh: int, sq: int, skv: int, d: int,

@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 BM, BN, BK = 128, 128, 128
 
 
@@ -93,7 +91,7 @@ def int8_matmul(x: jnp.ndarray, w: jnp.ndarray, row_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, row_scale, col_scale)
@@ -155,7 +153,7 @@ def int8_matmul_nt(g: jnp.ndarray, w: jnp.ndarray, fold_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bk), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(g, w, fold_scale, q_scale)
@@ -214,7 +212,7 @@ def int8_matmul_tn(x: jnp.ndarray, g: jnp.ndarray, fold_scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bk, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((k, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, g, fold_scale, q_scale)

@@ -16,15 +16,10 @@ import jax.numpy as jnp
 from repro.core.qconfig import Granularity, QuantSpec, RoundMode
 from repro.core.quantizer import quantize_int
 from repro.kernels import int8_matmul as _mm
+from repro.kernels import resolve_interpret
 from repro.kernels import qdq as _qdq
 
 _EPS = 1e-12
-
-
-def _auto_interpret(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jnp.ndarray, mult_r: int, mult_c: int) -> jnp.ndarray:
@@ -51,7 +46,7 @@ def fused_fake_quant(x: jnp.ndarray, spec: QuantSpec,
                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """Pallas-fused equivalent of core.quantizer.fake_quant_nograd for 2D+
     inputs with symmetric specs (the hot training path)."""
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     r, c = x2.shape
@@ -82,7 +77,7 @@ def int8_payload_linear(xq: jnp.ndarray, x_scale: jnp.ndarray,
     per-tensor ``w_scale``.  The shared core of the spec-driven, prepared and
     custom-vjp forward entries -- padding to MXU blocks, scale broadcast to
     the kernel's (M,1) x (1,N) layout, and the output slice live here once."""
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     m, n = xq.shape[0], wq.shape[1]
     row_scale = jnp.broadcast_to(x_scale.astype(jnp.float32).reshape(-1, 1),
                                  (m, 1))
@@ -158,7 +153,7 @@ def int8_bwd_dx(g: jnp.ndarray, wq: jnp.ndarray, w_scale: jnp.ndarray,
     """dx = qdq_token(g * w_scale) @ wq^T.  g: fp (M, N); wq: int8 (K, N)
     stored forward payload; w_scale: fp32 per-channel (1, N) or per-tensor
     (1, 1) -> (M, K) out_dtype."""
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     out_dtype = out_dtype or g.dtype
     m, n = g.shape
     k = wq.shape[0]
@@ -179,7 +174,7 @@ def int8_bwd_dw(xq: jnp.ndarray, x_scale: jnp.ndarray, g: jnp.ndarray,
     """dW = xq^T @ qdq_channel(g * x_scale).  xq: int8 (M, K) stored forward
     payload; x_scale: fp32 per-token (M, 1) or per-tensor (1, 1); g: fp
     (M, N) -> (K, N) out_dtype."""
-    interp = _auto_interpret(interpret)
+    interp = resolve_interpret(interpret)
     m, n = g.shape
     k = xq.shape[1]
     fold = jnp.broadcast_to(x_scale.astype(jnp.float32).reshape(-1, 1),

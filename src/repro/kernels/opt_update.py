@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import resolve_interpret
 # kernel tile row count; the shared REPRO_OPT_BLOCK knob (read at call time)
 # retiles this kernel and the qdq family together for autotune sweeps
 from repro.kernels.qdq import default_block_rows as tile_rows
@@ -123,7 +123,10 @@ def _adamw_kernel(sc_ref, g_ref, p_ref, q1_ref, s1_ref, z1_ref,
         upd = upd + wd * p
     delta = lr * upd
     po_ref[...] = (p - delta).astype(po_ref.dtype)
-    un_ref[0, 0] = jnp.sum(jnp.square(delta))
+    # one (8, 128) register tile per grid cell: the smallest lane-legal
+    # block for a per-tile scalar; the wrapper reads element [0, 0]
+    un_ref[...] = jnp.full(un_ref.shape, jnp.sum(jnp.square(delta)),
+                           jnp.float32)
 
     q1o_ref[...], s1o_ref[...], z1o_ref[...] = _requant(mom1, m1)
     q2o_ref[...], s2o_ref[...], z2o_ref[...] = _requant(mom2, m2)
@@ -138,7 +141,7 @@ def fused_adamw_blocks(g: jnp.ndarray, p: jnp.ndarray,
                        m1_codec: MomentCodec, m2_codec: MomentCodec,
                        weight_decay: bool,
                        block_rows: Optional[int] = None,
-                       interpret: bool = False):
+                       interpret: Optional[bool] = None):
     """One fused AdamW step over a (rows, block_size) bucket.
 
     ``g``/``p``: fp (rows, bs); ``m?_q``: int8 (rows, bs); ``m?_scale`` /
@@ -156,7 +159,7 @@ def fused_adamw_blocks(g: jnp.ndarray, p: jnp.ndarray,
     grid = (rows // br,)
     data = pl.BlockSpec((br, bs), lambda i: (i, 0))
     side = pl.BlockSpec((br, 1), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    part = pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0))
     out = pl.pallas_call(
         functools.partial(_adamw_kernel, m1=m1_codec, m2=m2_codec,
                           wd_on=weight_decay),
@@ -171,9 +174,10 @@ def fused_adamw_blocks(g: jnp.ndarray, p: jnp.ndarray,
                    jax.ShapeDtypeStruct((rows, bs), jnp.int8),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((grid[0], 1), jnp.float32)),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
-        interpret=interpret,
+                   jax.ShapeDtypeStruct((grid[0], 8, 128), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=resolve_interpret(interpret),
     )(scalars, g, p, m1_q, m1_scale, m1_zero, m2_q, m2_scale, m2_zero)
     p_new, q1, s1, z1, q2, s2, z2, un = out
-    return p_new, (q1, s1, z1), (q2, s2, z2), jnp.sum(un)
+    return p_new, (q1, s1, z1), (q2, s2, z2), jnp.sum(un[:, 0, 0])

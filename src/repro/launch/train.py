@@ -12,6 +12,8 @@ slice the same entry point builds the full mesh and sharded train step).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Optional, Sequence
 
 import jax
 
@@ -19,6 +21,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_shape, get_smoke_config
 from repro.core import fallback_policy, get_recipe, parse_policy
 from repro.data import Loader, SyntheticCorpus
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.optim import OptConfig
@@ -26,10 +29,10 @@ from repro.parallel.sharding import make_rules
 from repro.train import (FaultPlan, LoopConfig, SentinelConfig,
                          StabilitySentinel, Trainer, init_train_state,
                          make_eval_step, make_train_step)
-from repro.train.step import batch_shardings, state_shardings
+from repro.train.step import state_shardings, train_path_summary
 
 
-def main():
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
@@ -63,8 +66,29 @@ def main():
     ap.add_argument("--fault", default="",
                     help="deterministic fault-injection spec (overrides the "
                          "REPRO_FAULT env var), e.g. 'nan_grad@50'")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+@dataclasses.dataclass
+class TrainRun:
+    """Everything :func:`build` wires together: the config, the compiled-
+    to-be step (sharded under a multi-device mesh) and the trainer that
+    drives it.  ``summary`` is the ``train-path:`` line."""
+    cfg: object
+    model: object
+    recipe: object
+    opt: OptConfig
+    mesh: object
+    rules: object
+    step: object
+    trainer: Trainer
+    batch: int
+    seq: int
+    summary: str
+    faults: Optional[FaultPlan]
+
+
+def build(args: argparse.Namespace) -> TrainRun:
     if args.smoke:
         cfg = get_smoke_config(args.arch)
         batch = args.batch or 8
@@ -83,16 +107,9 @@ def main():
     rules = make_rules(mesh, "train", cfg=cfg) if multi else None
     opt = OptConfig(lr=args.lr, warmup_steps=max(args.steps // 20, 5),
                     total_steps=args.steps, state_storage=args.state_storage)
-    print(f"arch={cfg.name} devices={mesh.devices.size} "
-          f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"policy=[{recipe.describe()}] batch={batch} seq={seq}")
-    from repro.train.step import train_path_summary
     summary = train_path_summary(recipe, getattr(cfg, "n_layers", 0),
                                  opt_cfg=opt)
-    print(f"train-path: {summary}")
     faults = FaultPlan.from_env(args.fault or None)
-    if faults:
-        print(f"fault-plan: {faults.describe()}")
     sentinel = fallback_step = None
     if args.sentinel:
         sentinel = StabilitySentinel(SentinelConfig(
@@ -135,6 +152,24 @@ def main():
                           log_every=10),
                       sentinel=sentinel, fallback_step=fallback_step,
                       faults=faults if faults else None)
+    return TrainRun(cfg=cfg, model=model, recipe=recipe, opt=opt, mesh=mesh,
+                    rules=rules, step=step, trainer=trainer, batch=batch,
+                    seq=seq, summary=summary,
+                    faults=faults if faults else None)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    setup_compile_cache()
+    run = build(args)
+    mesh = run.mesh
+    print(f"arch={run.cfg.name} devices={mesh.devices.size} "
+          f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+          f"policy=[{run.recipe.describe()}] batch={run.batch} seq={run.seq}")
+    print(f"train-path: {run.summary}")
+    if run.faults:
+        print(f"fault-plan: {run.faults.describe()}")
+    trainer = run.trainer
     trainer.install_preemption_handler()
     trainer.maybe_resume()
     for rowd in trainer.run(rng=jax.random.PRNGKey(0)):
@@ -143,7 +178,7 @@ def main():
             extra += "  [fallback]"
         print(f"step {rowd['step']:5d}  ce={rowd['ce']:.4f}"
               f"  {rowd['sec_per_step']*1e3:.0f}ms/step{extra}", flush=True)
-    if args.sentinel or faults:
+    if args.sentinel or run.faults:
         print(f"resilience: {trainer.resilience_summary()}")
 
 

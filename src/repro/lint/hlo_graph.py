@@ -143,6 +143,19 @@ class HloModule:
         header = self.text.splitlines()[0] if self.text else ""
         return {int(p) for p in _ALIAS_ENTRY.findall(_alias_blob(header))}
 
+    def operand_types(self, comp: str, ins: Instr
+                      ) -> List[Tuple[str, Tuple[int, ...]]]:
+        """(dtype, dims) per operand: inline-typed operand lists are read as
+        they stand; bare ``%name`` operands (the compiled-module printer's
+        form) resolve through their defining instruction in ``comp``."""
+        inline = operand_types(ins)
+        if inline:
+            return inline
+        defs = self.defs(comp)
+        shapes = [shape_of(defs[n].type_str) for n in operand_names(ins)
+                  if n in defs]
+        return [(d, dims) for d, dims in shapes if d is not None]
+
     # -- dataflow ----------------------------------------------------------
 
     def walk_back(self, comp: str, ins: Instr,

@@ -28,7 +28,7 @@ import itertools
 from typing import Any, Dict, List, Sequence, Set, Tuple
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.core.qadam import QState
 from repro.lint.rules import Finding, Severity

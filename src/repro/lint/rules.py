@@ -29,8 +29,7 @@ import enum
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.lint.hlo_graph import (ALIASING_OPS, QUANT_LOCAL_OPS, HloModule,
-                                  nbytes, nelems, operand_names,
-                                  operand_types, shape_of)
+                                  nbytes, nelems, operand_names, shape_of)
 
 
 class Severity(enum.IntEnum):
@@ -131,7 +130,7 @@ def _no_whole_cache_dequant(mod: HloModule, *, min_elems: int = 4096,
             continue
         if dims is not None and res_dims != tuple(dims):
             continue
-        opnds = operand_types(ins)
+        opnds = mod.operand_types(comp, ins)
         if opnds and opnds[0][0] == from_dtype:
             out.append(_finding(
                 "no-whole-cache-dequant",

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.qpolicy import LinearCtx, as_policy
-from repro.parallel.compat import axis_size, shard_map
 from repro.models.common import ACT_FNS, ParamSpec
 
 
@@ -153,7 +152,7 @@ def _alltoall_moe(x2, params, cfg, policy, tp_axis: str, layer, n_layers):
     """Per-shard body (tokens already split over tp_axis; expert weights
     already sharded over tp_axis): route locally, all_to_all to expert
     owners, FFN, all_to_all back, combine."""
-    tp = axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     t_loc, d = x2.shape
     e, k = cfg.n_experts, cfg.top_k
     e_loc = e // tp
@@ -227,8 +226,8 @@ def moe_apply(params, x: jnp.ndarray, cfg, *,
             "w_router": P(None, None), "w_gate": P(None, None, None),
             "w_up": P(None, None, None), "w_down": P(None, None, None)})
         out_specs = (P(dp_axes, None, None), P(), P())
-        y, aux, z = shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False)(
+        y, aux, z = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                      out_specs=out_specs, check_vma=False)(
             x, {k: params[k] for k in
                 ("w_router", "w_gate", "w_up", "w_down")})
         return y, jnp.mean(aux), jnp.mean(z)
@@ -250,8 +249,8 @@ def moe_apply(params, x: jnp.ndarray, cfg, *,
             "w_down": P(tp_axis, None, None),
         })
         out_specs = (P(dp_axes, tp_axis, None), P(), P())
-        y, aux, z = shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False)(
+        y, aux, z = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                      out_specs=out_specs, check_vma=False)(
             x, {k: params[k] for k in
                 ("w_router", "w_gate", "w_up", "w_down")})
         return y, jnp.mean(aux), jnp.mean(z)
@@ -295,8 +294,8 @@ def moe_apply(params, x: jnp.ndarray, cfg, *,
             "w_router": P(None, None), "w_gate": P(tp_axis, None, None),
             "w_up": P(tp_axis, None, None), "w_down": P(tp_axis, None, None)})
         out_specs = (P(dp_axes, None, None), P(), P())
-        y, aux, z = shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False)(
+        y, aux, z = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                      out_specs=out_specs, check_vma=False)(
             x, {k: params[k] for k in
                 ("w_router", "w_gate", "w_up", "w_down")})
         return y, jnp.mean(aux), jnp.mean(z)
@@ -316,7 +315,7 @@ def moe_apply(params, x: jnp.ndarray, cfg, *,
         "w_up": P(None, None, tp_axis),
         "w_down": P(None, tp_axis, None)})
     out_specs = (P(dp_axes, None, None), P(), P())
-    y, aux, z = shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)(
+    y, aux, z = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                  out_specs=out_specs, check_vma=False)(
         x, {k: params[k] for k in ("w_router", "w_gate", "w_up", "w_down")})
     return y, jnp.mean(aux), jnp.mean(z)

@@ -186,8 +186,7 @@ def _fused_bucket(idxs: List[int], p_leaves, g_leaves, m1_leaves, m2_leaves,
     p_new, m1_new, m2_new, sumsq = _ok.fused_adamw_blocks(
         g_cat, p_cat, q1, s1, z1, q2, s2, z2, scalars,
         m1_codec=_ok.codec_of(m1_spec), m2_codec=_ok.codec_of(m2_spec),
-        weight_decay=bool(cfg.weight_decay), block_rows=min(br, rows + pad),
-        interpret=jax.default_backend() != "tpu")
+        weight_decay=bool(cfg.weight_decay), block_rows=min(br, rows + pad))
 
     out_p, out_m1, out_m2 = {}, {}, {}
     off = 0

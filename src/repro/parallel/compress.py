@@ -24,7 +24,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.compat import axis_size, shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -45,7 +44,7 @@ def _dequant(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
 def int8_psum_flat(flat: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Per-shard body: compressed psum of a replicated flat fp32 vector.
     flat length must be divisible by the axis size."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     chunks = flat.reshape(n, -1)                     # (n_dev, chunk)
     q, s = _quant_chunks(chunks)
@@ -73,14 +72,14 @@ def compressed_allreduce(tree, mesh: Mesh, axis_name: str):
                             for x in leaves])
 
     def body(v):
-        nn = axis_size(axis_name)
+        nn = jax.lax.axis_size(axis_name)
         padlen = (-v.size) % nn
         vp = jnp.pad(v, (0, padlen))
         out = int8_psum_flat(vp, axis_name)
         return out[:v.size]
 
-    out = shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
-                        check_vma=False)(flat)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_vma=False)(flat)
     parts = []
     off = 0
     for x, size in zip(leaves, sizes):

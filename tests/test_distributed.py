@@ -15,7 +15,8 @@ def test_mini_dryrun_train_compiles_on_mesh(forced8_run):
         from repro.parallel.sharding import make_rules
         from repro.train.step import (init_train_state, make_train_step,
                                       state_shardings, batch_shardings)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for arch in ("llama3-8b", "mamba2-130m"):
             cfg = get_smoke_config(arch)
             model = build_model(cfg)
@@ -49,7 +50,8 @@ def test_moe_shard_map_modes_match_local(forced8_run):
         from repro.models.moe import moe_apply, moe_spec
         from repro.models.common import init_from_spec
         from repro.parallel.sharding import make_rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         for arch, s in (("phi3.5-moe-42b-a6.6b", 8),    # E=4 % tp=4 -> a2a
                         ("phi3.5-moe-42b-a6.6b", 3),    # s%tp!=0 -> masked
                         ("granite-moe-3b-a800m", 8)):   # E=8, ff d32%4 -> a2a
@@ -76,9 +78,9 @@ def test_compressed_allreduce_close_to_exact(forced8_run):
     print(forced8_run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import shard_map
         from repro.parallel.compress import int8_psum_flat
-        mesh = jax.make_mesh((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         v = jax.random.normal(jax.random.PRNGKey(0), (8, 4096))
 
         def body(vb):
@@ -87,9 +89,9 @@ def test_compressed_allreduce_close_to_exact(forced8_run):
             return int8_psum_flat(mine, "d")[None, :]
 
         with mesh:
-            got = jax.jit(shard_map(body, mesh=mesh, in_specs=P("d", None),
-                                    out_specs=P("d", None),
-                                    check_vma=False))(v)
+            got = jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=P("d", None),
+                out_specs=P("d", None), check_vma=False))(v)
         # every rank's compressed sum approximates the true sum of all rows
         want = jnp.sum(v, axis=0)
         got0 = got[0]
@@ -107,9 +109,9 @@ def test_compressed_allreduce_tree_matches_fp_psum(forced8_run):
     print(forced8_run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.compat import shard_map
         from repro.parallel.compress import compressed_allreduce
-        mesh = jax.make_mesh((8,), ("d",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("d",))
         k = jax.random.PRNGKey(0)
         tree = {"w": jax.random.normal(k, (64, 96)),
                 "b": jax.random.normal(jax.random.fold_in(k, 1), (131,))}
@@ -121,8 +123,8 @@ def test_compressed_allreduce_tree_matches_fp_psum(forced8_run):
                 lambda x: jax.lax.psum(x, "d"), t)
 
         with mesh:
-            want = jax.jit(shard_map(fp_body, mesh=mesh, in_specs=P(),
-                                     out_specs=P(), check_vma=False))(tree)
+            want = jax.jit(jax.shard_map(fp_body, mesh=mesh, in_specs=P(),
+                                         out_specs=P(), check_vma=False))(tree)
         assert jax.tree_util.tree_structure(got) == \
             jax.tree_util.tree_structure(tree)
         for name in tree:
@@ -141,7 +143,8 @@ def test_serve_prefill_decode_sharded(forced8_run):
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.parallel.sharding import make_rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke_config("llama3-8b")
         model = build_model(cfg)
         params = model.init_params(jax.random.PRNGKey(0))

@@ -219,7 +219,8 @@ def test_state_shardings_bucketed_layout(monkeypatch):
     from repro.models import build_model
     from repro.parallel.sharding import make_rules
     from repro.train.step import init_train_state, state_shardings
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, "train")
     cfg = get_smoke_config("gpt2-small")
     model = build_model(cfg)
