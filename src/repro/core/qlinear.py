@@ -46,6 +46,7 @@ def _flat2d(a: jnp.ndarray) -> jnp.ndarray:
     return a.reshape(-1, a.shape[-1])
 
 
+@jax.named_scope("qdq")
 def _train_fake_quant(x: jnp.ndarray, spec, key=None) -> jnp.ndarray:
     """``fake_quant_nograd`` with the hot symmetric 2-D cases routed through
     the fused Pallas kernel (one HBM round trip instead of three -- see

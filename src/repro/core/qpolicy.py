@@ -357,7 +357,8 @@ class QuantPolicy:
         """The quantized matmul: resolve (role, layer) -> spec+backend, run.
         ``b`` is an optional bias added outside the quantized op (biases are
         not quantized -- paper scope is the matmul)."""
-        y = self._matmul(ctx, x, w)
+        with jax.named_scope("linear"):
+            y = self._matmul(ctx, x, w)
         return y if b is None else y + b
 
     def _matmul(self, ctx, x, w):

@@ -119,6 +119,7 @@ def _fake_quant_blockwise(x: jnp.ndarray, spec: QuantSpec,
     return deq.reshape(-1)[:n].reshape(x.shape).astype(orig_dtype)
 
 
+@jax.named_scope("qdq")
 def fake_quant_nograd(x: jnp.ndarray, spec: QuantSpec,
                       key: Optional[jax.Array] = None) -> jnp.ndarray:
     """Fake quantization *without* gradient pass-through (used on values that
@@ -171,6 +172,7 @@ def storage_dtype(bits: int):
     return jnp.int8 if bits <= 8 else jnp.int16
 
 
+@jax.named_scope("qdq")
 def quantize_int(x: jnp.ndarray, spec: QuantSpec,
                  key: Optional[jax.Array] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -195,6 +197,7 @@ def quantize_int(x: jnp.ndarray, spec: QuantSpec,
     return q.astype(storage_dtype(spec.bits)), scale, zero
 
 
+@jax.named_scope("qdq")
 def dequantize_int(q: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
                    spec: QuantSpec, shape=None, dtype=jnp.float32) -> jnp.ndarray:
     """Inverse of :func:`quantize_int`.  ``shape`` is required for blockwise

@@ -259,6 +259,7 @@ def decode_attention(q: jnp.ndarray,
     ctx, okq, oks, ovq, ovs = pl.pallas_call(
         functools.partial(_decode_attn_kernel, bk=bk, nblk=nblk, scale=scale,
                           qmin=qmin, qmax=qmax),
+        name="_decode_attn_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),
@@ -360,6 +361,7 @@ def decode_attention_paged(q: jnp.ndarray,
     ctx, okq, oks, ovq, ovs = pl.pallas_call(
         functools.partial(_paged_decode_attn_kernel, bk=page, nblk=maxp,
                           scale=scale, qmin=qmin, qmax=qmax),
+        name="_paged_decode_attn_kernel",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, kh, g, hd), q.dtype),

@@ -111,6 +111,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     return pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, q_offset=q_offset),
+        name="_flash_fwd_kernel",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -270,6 +271,7 @@ def _fwd_with_lse(q, k, v, causal, q_offset, block_q, block_k, interpret):
     return pl.pallas_call(
         functools.partial(_flash_fwd_lse_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, q_offset=q_offset),
+        name="_flash_fwd_lse_kernel",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -338,6 +340,7 @@ def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkdv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, q_offset=q_offset),
+        name="_flash_bwd_dkdv_kernel",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),   # q
@@ -363,6 +366,7 @@ def _fa_bwd(causal, q_offset, block_q, block_k, interpret, res, g):
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, q_offset=q_offset),
+        name="_flash_bwd_dq_kernel",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),   # q
@@ -472,6 +476,7 @@ def flash_attention_fwd_q8(q: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_flash_fwd_q8_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, q_offset=q_offset),
+        name="_flash_fwd_q8_kernel",
         grid=(b, nq, nk),
         in_specs=[q_tile, kv_tile, kv_scale, kv_tile, kv_scale],
         out_specs=q_tile,

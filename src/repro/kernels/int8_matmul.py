@@ -81,6 +81,7 @@ def int8_matmul(x: jnp.ndarray, w: jnp.ndarray, row_scale: jnp.ndarray,
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
         functools.partial(_int8_matmul_kernel, nk=grid[2]),
+        name="_int8_matmul_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -143,6 +144,7 @@ def int8_matmul_nt(g: jnp.ndarray, w: jnp.ndarray, fold_scale: jnp.ndarray,
     grid = (m // bm, k // bk, n // bn)
     return pl.pallas_call(
         functools.partial(_int8_matmul_nt_kernel, nk=grid[2]),
+        name="_int8_matmul_nt_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, kk)),
@@ -202,6 +204,7 @@ def int8_matmul_tn(x: jnp.ndarray, g: jnp.ndarray, fold_scale: jnp.ndarray,
     grid = (k // bk, n // bn, m // bm)
     return pl.pallas_call(
         functools.partial(_int8_matmul_tn_kernel, nk=grid[2]),
+        name="_int8_matmul_tn_kernel",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (kk, i)),

@@ -157,11 +157,12 @@ def int8_bwd_dx(g: jnp.ndarray, wq: jnp.ndarray, w_scale: jnp.ndarray,
     out_dtype = out_dtype or g.dtype
     m, n = g.shape
     k = wq.shape[0]
-    fold = jnp.broadcast_to(w_scale.astype(jnp.float32).reshape(1, -1),
-                            (1, n))
-    absmax = jnp.max(jnp.abs(g.astype(jnp.float32)) * fold, axis=1,
-                     keepdims=True)
-    q_scale = jnp.maximum(absmax, _EPS) / 127.0
+    with jax.named_scope("qdq"):
+        fold = jnp.broadcast_to(w_scale.astype(jnp.float32).reshape(1, -1),
+                                (1, n))
+        absmax = jnp.max(jnp.abs(g.astype(jnp.float32)) * fold, axis=1,
+                         keepdims=True)
+        q_scale = jnp.maximum(absmax, _EPS) / 127.0
     out = _mm.int8_matmul_nt(_pad_to(g, 128, 128), _pad_to(wq, 128, 128),
                              _pad_to(fold, 1, 128), _pad_to(q_scale, 128, 1),
                              out_dtype=out_dtype, interpret=interp)
@@ -177,11 +178,12 @@ def int8_bwd_dw(xq: jnp.ndarray, x_scale: jnp.ndarray, g: jnp.ndarray,
     interp = resolve_interpret(interpret)
     m, n = g.shape
     k = xq.shape[1]
-    fold = jnp.broadcast_to(x_scale.astype(jnp.float32).reshape(-1, 1),
-                            (m, 1))
-    absmax = jnp.max(jnp.abs(g.astype(jnp.float32)) * fold, axis=0,
-                     keepdims=True)
-    q_scale = jnp.maximum(absmax, _EPS) / 127.0
+    with jax.named_scope("qdq"):
+        fold = jnp.broadcast_to(x_scale.astype(jnp.float32).reshape(-1, 1),
+                                (m, 1))
+        absmax = jnp.max(jnp.abs(g.astype(jnp.float32)) * fold, axis=0,
+                         keepdims=True)
+        q_scale = jnp.maximum(absmax, _EPS) / 127.0
     out = _mm.int8_matmul_tn(_pad_to(xq, 128, 128), _pad_to(g, 128, 128),
                              _pad_to(fold, 128, 1), _pad_to(q_scale, 1, 128),
                              out_dtype=out_dtype, interpret=interp)

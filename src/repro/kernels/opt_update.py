@@ -163,6 +163,7 @@ def fused_adamw_blocks(g: jnp.ndarray, p: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_adamw_kernel, m1=m1_codec, m2=m2_codec,
                           wd_on=weight_decay),
+        name="_adamw_kernel",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   data, data, data, side, side, data, side, side],

@@ -58,6 +58,7 @@ def qdq_row(x: jnp.ndarray, bits: int = 8,
     grid = (pl.cdiv(rows, block_rows),)
     return pl.pallas_call(
         functools.partial(_qdq_row_kernel, qmax=qmax),
+        name="_qdq_row_kernel",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, feat), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, feat), lambda i: (i, 0)),
@@ -78,6 +79,7 @@ def qdq_scaled(x: jnp.ndarray, scale: jnp.ndarray, bits: int = 8,
     scol = scale.shape[1]
     return pl.pallas_call(
         functools.partial(_qdq_scaled_kernel, qmax=qmax),
+        name="_qdq_scaled_kernel",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, feat), lambda i: (i, 0)),
                   pl.BlockSpec((1, scol), lambda i: (0, 0))],

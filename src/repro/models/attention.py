@@ -495,8 +495,9 @@ def attn_apply(params, x: jnp.ndarray, cfg, *,
             k, v = ck, cv
 
     if ctx is None:
-        ctx = _gqa_attend(q, k, v, mask, rules,
-                          impl=getattr(cfg, "attention_impl", "xla"))
+        with jax.named_scope("attention"):
+            ctx = _gqa_attend(q, k, v, mask, rules,
+                              impl=getattr(cfg, "attention_impl", "xla"))
     # named for the remat policy: saving ctx prunes one full score-chain
     # recompute from the backward (EXPERIMENTS.md Section Perf iter 4)
     ctx = checkpoint_name(ctx, "attn_ctx")

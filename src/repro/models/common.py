@@ -129,6 +129,7 @@ def layernorm(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
             + bias.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def apply_norm(x, params, kind: str):
     if kind == "rmsnorm":
         return rmsnorm(x, params["scale"])

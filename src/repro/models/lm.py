@@ -69,6 +69,7 @@ def lm_spec(cfg: ArchConfig) -> Dict:
 # Embedding / head
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(params, tokens: jnp.ndarray, cfg, positions=None,
                  dtype=None, policy=None) -> jnp.ndarray:
     """Token (+learned position) embedding.  The ``embed`` role governs a
@@ -88,6 +89,7 @@ def embed_tokens(params, tokens: jnp.ndarray, cfg, positions=None,
     return e
 
 
+@jax.named_scope("head")
 def logits_chunk(params, h: jnp.ndarray, cfg, policy=None) -> jnp.ndarray:
     """(B, C, d) -> (B, C, V_padded) in fp32, padded tail masked to -inf.
     The ``lm_head`` role governs a weight-only qdq of the head matrix (the
@@ -120,6 +122,7 @@ def _chunk_len(s: int, target: int) -> int:
     return s
 
 
+@jax.named_scope("head")
 def chunked_ce(params, h: jnp.ndarray, labels: jnp.ndarray,
                mask: Optional[jnp.ndarray], cfg, rules,
                policy=None) -> jnp.ndarray:
@@ -180,10 +183,11 @@ def _scan_blocks(params, h, cfg, *, policy, rules, positions, mask,
             policy=jax.checkpoint_policies.save_only_these_names("attn_ctx"))
 
     zero = jnp.zeros((), jnp.float32)
-    (h, aux, z), (ncaches, nssts) = jax.lax.scan(
-        body, (h, zero, zero),
-        (params["blocks"], caches, ssm_states,
-         jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    with jax.named_scope("layers"):
+        (h, aux, z), (ncaches, nssts) = jax.lax.scan(
+            body, (h, zero, zero),
+            (params["blocks"], caches, ssm_states,
+             jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     return h, ncaches, nssts, aux, z
 
 
@@ -242,8 +246,9 @@ def _hybrid_blocks(params, h, cfg, *, policy, rules, positions, mask,
             policy=jax.checkpoint_policies.save_only_these_names("attn_ctx"))
 
     zero = jnp.zeros((), jnp.float32)
-    (h, aux, z), (nssm, ncaches) = jax.lax.scan(
-        group_body, (h, zero, zero), (grouped, g_ssm, caches, layer_ids))
+    with jax.named_scope("layers"):
+        (h, aux, z), (nssm, ncaches) = jax.lax.scan(
+            group_body, (h, zero, zero), (grouped, g_ssm, caches, layer_ids))
     if nssm is not None:
         nssm = jax.tree_util.tree_map(
             lambda x: x.reshape(cfg.n_layers, *x.shape[2:]), nssm)

@@ -138,8 +138,9 @@ def make_train_step(model: Model, recipe, opt_cfg: OptConfig, rules=None,
 
     def loss_fn(params, batch, rng):
         from repro.models.common import cast_params
-        compute_params = constrain_like_params(
-            cast_params(params, jnp.bfloat16), params)
+        with jax.named_scope("weights_cast"):
+            compute_params = constrain_like_params(
+                cast_params(params, jnp.bfloat16), params)
         loss, metrics = model.train_loss(compute_params, batch,
                                          policy=policy, rules=rules, rng=rng)
         return loss, metrics
@@ -179,8 +180,9 @@ def make_train_step(model: Model, recipe, opt_cfg: OptConfig, rules=None,
             metrics.update(grad_quant_health(
                 grads, state.opt.m1, policy.adam_m1,
                 _health_err_spec(policy), beta1=opt_cfg.b1))
-        new_params, new_opt, stats = adamw_update(
-            state.params, grads, state.opt, opt_cfg, policy)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, stats = adamw_update(
+                state.params, grads, state.opt, opt_cfg, policy)
         metrics = dict(metrics)
         metrics.update(stats)
         return TrainState(new_params, new_opt), metrics
