@@ -187,7 +187,11 @@ def int8_bwd_dw(xq: jnp.ndarray, x_scale: jnp.ndarray, g: jnp.ndarray,
     out = _mm.int8_matmul_tn(_pad_to(xq, 128, 128), _pad_to(g, 128, 128),
                              _pad_to(fold, 128, 1), _pad_to(q_scale, 1, 128),
                              out_dtype=out_dtype, interpret=interp)
-    return out[:k, :n]
+    # A layer scan stacks dW per layer, and the TPU compiler fuses the
+    # kernel into that update; the fusion gets the default 16 MiB of scoped
+    # VMEM whatever the kernel asks for, which the picked tiles exceed.
+    # The barrier keeps the kernel a call of its own.
+    return jax.lax.optimization_barrier(out)[:k, :n]
 
 
 @partial(jax.jit, static_argnames=("out_dtype", "interpret"))

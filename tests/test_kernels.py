@@ -147,6 +147,85 @@ def test_int8_matmul_tn_matches_ref(m, k, n):
     assert np.isfinite(np.asarray(got)).all()
 
 
+def _layout_args(layout, m, k, n, rng):
+    """Padded operands of one int8 layout on the (m, k, n) of its kernel's
+    docstring, with the zero padding (and zero scales) of ops.py."""
+    pm, pk, pn = (-m) % 128, (-k) % 128, (-n) % 128
+    ints = lambda r, c: jnp.asarray(rng.randint(-128, 128, (r, c)), jnp.int8)
+    pos = lambda r, c: jnp.asarray(rng.rand(r, c).astype(np.float32) + 0.01)
+    pad = lambda a, r, c: jnp.pad(a, ((0, r), (0, c)))
+    if layout == "fwd":
+        return (pad(ints(m, k), pm, pk), pad(ints(k, n), pk, pn),
+                pad(pos(m, 1), pm, 0), pad(pos(1, n), 0, pn))
+    g = jnp.asarray(rng.randn(m, n).astype(np.float32), jnp.bfloat16)
+    gf = g.astype(jnp.float32)
+    if layout == "nt":
+        fold = pos(1, n)
+        qs = jnp.max(jnp.abs(gf) * fold, axis=1, keepdims=True) / 127.0
+        return (pad(g, pm, pn), pad(ints(k, n), pk, pn), pad(fold, 0, pn),
+                pad(qs, pm, 0))
+    fold = pos(m, 1)
+    qs = jnp.max(jnp.abs(gf) * fold, axis=0, keepdims=True) / 127.0
+    return (pad(ints(m, k), pm, pk), pad(g, pm, pn), pad(fold, pm, 0),
+            pad(qs, 0, pn))
+
+
+@pytest.mark.parametrize("layout", ["fwd", "nt", "tn"])
+@pytest.mark.parametrize("m,k,n", [(300, 200, 1376), (130, 257, 90)])
+def test_picked_tiles_bit_identical_to_128_grid(layout, m, k, n):
+    """Re-tiling moves no number: int32 accumulation is exact and the quant
+    prologue and dequant epilogue are elementwise, so the picked tiles give
+    the 128^3 grid's output bit for bit (ragged shapes, padding lanes with
+    zero scales included)."""
+    from repro.kernels import int8_matmul as mm
+    fn = {"fwd": mm.int8_matmul, "nt": mm.int8_matmul_nt,
+          "tn": mm.int8_matmul_tn}[layout]
+    args = _layout_args(layout, m, k, n, np.random.RandomState(6))
+    picked = fn(*args, out_dtype=jnp.float32, interpret=True)
+    grid128 = fn(*args, out_dtype=jnp.float32, bm=128, bn=128, bk=128,
+                 interpret=True)
+    mp, kp, np_ = (-(-d // 128) * 128 for d in (m, k, n))
+    t = mm.pick_tiles(layout, mp, kp, np_, jnp.float32,
+                      None if layout == "fwd" else jnp.bfloat16)
+    assert (t.bm, t.bn, t.bk) != (128, 128, 128)
+    assert np.isfinite(np.asarray(picked)).all()
+    np.testing.assert_array_equal(np.asarray(picked), np.asarray(grid128))
+
+
+@pytest.mark.parametrize("layout", ["fwd", "nt", "tn"])
+@pytest.mark.parametrize("m,k,n", [
+    (16384, 768, 2304), (16384, 768, 768), (16384, 768, 3072),
+    (16384, 3072, 768), (16384, 4096, 4096), (16384, 4096, 512),
+    (16384, 4096, 11008), (16384, 11008, 4096), (128, 768, 2304),
+    (384, 256, 1408), (64, 128, 128)])
+def test_pick_tiles_divide_and_fit(layout, m, k, n):
+    """The picked tiles are multiples of 128 (or a whole dimension), divide
+    the padded dimensions, hold every double-buffered block and the int32
+    accumulator within the VMEM bytes reported, and stay in the budget;
+    M <= 128 (a serving batch) takes the whole of M."""
+    from repro.kernels import int8_matmul as mm
+    out = jnp.bfloat16
+    g = None if layout == "fwd" else jnp.bfloat16
+    t = mm.pick_tiles(layout, m, k, n, out, g)
+    for b, d in ((t.bm, m), (t.bn, n), (t.bk, k)):
+        assert b == d or b % 128 == 0, (b, d)
+        assert d % b == 0, (b, d)
+    if m <= 128:
+        assert t.bm == m
+    g_b = 2 if g is not None else 0
+    ins = {"fwd": t.bm * t.bk + t.bk * t.bn,
+           "nt": g_b * t.bm * t.bn + t.bk * t.bn,
+           "tn": t.bm * t.bk + g_b * t.bm * t.bn}[layout]
+    out_elems = {"fwd": t.bm * t.bn, "nt": t.bm * t.bk,
+                 "tn": t.bk * t.bn}[layout]
+    assert 2 * (ins + 2 * out_elems) + 4 * out_elems <= t.vmem_bytes
+    assert t.vmem_bytes == mm.vmem_bytes(layout, t.bm, t.bn, t.bk, 2, g_b)
+    assert t.vmem_bytes <= mm.VMEM_BUDGET
+    params = t.compiler_params()
+    assert params.vmem_limit_bytes == (
+        t.vmem_bytes if t.vmem_bytes > mm.DEFAULT_SCOPED_VMEM else None)
+
+
 def test_zero_scale_padding_guard():
     """0-scale rows/cols (zero-padding of ragged shapes) must not emit
     NaN/Inf from the quant prologue's division or the dequant epilogue."""

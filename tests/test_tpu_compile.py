@@ -109,6 +109,62 @@ KERNELS = {
 }
 
 
+#: the int8 matmuls' (M, K, N) in the benchmark's training cells (16,384
+#: tokens; gpt2-small's four block linears, the cut Yi-6B's four) and in
+#: int8 serving (64 slots padded to 128 rows, gpt2-small's qkv)
+INT8_SHAPES = [(16384, 768, 2304), (16384, 768, 768), (16384, 768, 3072),
+               (16384, 3072, 768), (16384, 4096, 4096), (16384, 4096, 512),
+               (16384, 4096, 11008), (16384, 11008, 4096), (128, 768, 2304)]
+
+
+def _int8_call(layout, m, k, n):
+    """One int8 matmul with its picked tiles, as the training step calls it
+    (bf16 activations and gradients, bf16 results)."""
+    if layout == "fwd":
+        return (functools.partial(mm.int8_matmul, out_dtype=BF16),
+                [((m, k), I8), ((k, n), I8), ((m, 1), F32), ((1, n), F32)])
+    if layout == "nt":
+        return (functools.partial(mm.int8_matmul_nt, out_dtype=BF16),
+                [((m, n), BF16), ((k, n), I8), ((1, n), F32), ((m, 1), F32)])
+    return (functools.partial(mm.int8_matmul_tn, out_dtype=BF16),
+            [((m, k), I8), ((m, n), BF16), ((m, 1), F32), ((1, n), F32)])
+
+
+@pytest.mark.parametrize("layout", ["fwd", "nt", "tn"])
+@pytest.mark.parametrize("m,k,n", INT8_SHAPES)
+def test_int8_matmul_picked_tiles_compile_for_v5e(one_chip, layout, m, k, n):
+    """The picked tiles (and the VMEM limit they raise) are ones the TPU
+    compiler accepts at every shape the cells and serving run."""
+    t = mm.pick_tiles(layout, m, k, n, BF16, None if layout == "fwd"
+                      else BF16)
+    assert (t.bm, t.bn, t.bk) != (128, 128, 128) or m == 128
+    fn, shapes = _int8_call(layout, m, k, n)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (layout, t)
+
+
+def test_int8_weight_grad_compiles_inside_a_layer_scan(one_chip):
+    """A layer scan stacks each layer's weight gradient.  The TPU compiler
+    would fuse the weight-gradient kernel into that update and hold the
+    fusion to the default 16 MiB of scoped VMEM, below what the picked
+    tiles ask; ``ops.int8_bwd_dw`` keeps the kernel a call of its own."""
+    from repro.kernels import ops
+    layers, m, k, n = 2, 16384, 768, 3072
+
+    def stacked_dw(xq, xs, g):
+        def body(carry, layer):
+            return carry, ops.int8_bwd_dw(*layer, out_dtype=BF16,
+                                          interpret=False)
+        return jax.lax.scan(body, 0, (xq, xs, g))[1]
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            [((layers, m, k), I8), ((layers, m, 1), F32),
+             ((layers, m, n), BF16)]]
+    compiled = jax.jit(stacked_dw).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = KERNELS[name]
