@@ -1,0 +1,9 @@
+"""The int8 input-gradient matmul, (M, N) @ (K, N)^T: 2 * M * K * N
+operations at the int8 peak."""
+from chipbench.flops import matmul_ops
+
+PRECISION = "int8"
+
+
+def ops(call) -> float:
+    return matmul_ops(call, 0)

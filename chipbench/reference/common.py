@@ -1,5 +1,6 @@
 """Pieces shared by the plain references: initialisation, causal attention
-in query blocks, the cross entropy, and the batch loss."""
+in query blocks, the cross entropy, the batch loss, and the operation
+counts of ``chipbench.flops``' convention."""
 from __future__ import annotations
 
 import math
@@ -50,6 +51,26 @@ def fill(key, shapes: Dict, rule: Callable[[str, tuple], tuple]) -> Dict:
             node = node.setdefault(k, {})
         node[path[-1]] = x
     return out
+
+
+def stacked_matrix_weights(blocks: Dict) -> int:
+    """Weights of every matrix in a nested dict of shapes stacked along a
+    leading layer axis: each (L, in, out) leaf counts L * in * out; biases
+    and gains, (L, n), are no matrices."""
+    tot = 0
+    for v in blocks.values():
+        if isinstance(v, dict):
+            tot += stacked_matrix_weights(v)
+        elif len(v) == 3:
+            tot += v[0] * v[1] * v[2]
+    return tot
+
+
+def attention_ops(layers: int, seq: int, heads: int, d_qk: int,
+                  d_v: int) -> float:
+    """Training operations per token of the attention scores and their use
+    at context ``seq``: 6 * L * s * h * (d_qk + d_v)."""
+    return 6.0 * layers * seq * heads * (d_qk + d_v)
 
 
 def causal_attention(q, k, v):

@@ -15,8 +15,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference.common import (causal_attention, cross_entropy_sum,
-                                        fill, mm, vocab_rows)
+from chipbench.reference.common import (attention_ops, causal_attention,
+                                        cross_entropy_sum, fill, mm,
+                                        stacked_matrix_weights, vocab_rows)
 
 
 def dims(conf: Dict) -> Dict:
@@ -106,3 +107,25 @@ def seq_loss(params, row, conf: Dict):
     return cross_entropy_sum(x, params["embed"].T, row[1:],
                              dims(conf)["V"])
 
+
+def train_ops_per_token(conf: Dict, seq: int) -> Dict[str, float]:
+    """``linears``: 6 per weight of the blocks' projections and MLP;
+    ``other``: the tied head and attention (``chipbench.flops``)."""
+    m = dims(conf)
+    hd = m["d"] // m["h"]
+    return {"linears": 6.0 * stacked_matrix_weights(shapes(conf)["blocks"]),
+            "other": 6.0 * m["d"] * m["V"]
+            + attention_ops(m["L"], seq, m["h"], hd, hd)}
+
+
+def tiny(conf: Dict, d: int) -> Dict:
+    """``conf`` cut for the CPU tests: 2 layers of width ``d``, 2 heads, a
+    vocabulary of 4d + 44 and 64 positions, in the file's keys and the
+    program's fields alike."""
+    conf = dict(conf, n_layer=2, n_embd=d, n_head=2, n_positions=64,
+                n_ctx=64, vocab_size=4 * d + 44)
+    prog = conf["program"]
+    conf["program"] = dict(prog, fields=dict(
+        prog["fields"], n_layers=2, d_model=d, n_heads=2, n_kv_heads=2,
+        head_dim=d // 2, d_ff=4 * d, vocab_size=4 * d + 44, max_seq=64))
+    return conf

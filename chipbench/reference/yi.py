@@ -14,8 +14,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference.common import (causal_attention, cross_entropy_sum,
-                                        fill, mm, vocab_rows)
+from chipbench.reference.common import (attention_ops, causal_attention,
+                                        cross_entropy_sum, fill, mm,
+                                        stacked_matrix_weights, vocab_rows)
 
 
 def dims(conf: Dict) -> Dict:
@@ -105,3 +106,27 @@ def seq_loss(params, row, conf: Dict):
         x = layer(x, jax.tree_util.tree_map(lambda t: t[i], blocks))
     x = _rms(x, params["final_norm"]["scale"], m["eps"])
     return cross_entropy_sum(x, params["lm_head"], labels, m["V"])
+
+
+def train_ops_per_token(conf: Dict, seq: int) -> Dict[str, float]:
+    """``linears``: 6 per weight of the blocks' projections and gated MLP;
+    ``other``: the untied head and attention (``chipbench.flops``)."""
+    m = dims(conf)
+    return {"linears": 6.0 * stacked_matrix_weights(shapes(conf)["blocks"]),
+            "other": 6.0 * m["d"] * m["V"]
+            + attention_ops(m["L"], seq, m["h"], m["hd"], m["hd"])}
+
+
+def tiny(conf: Dict, d: int) -> Dict:
+    """``conf`` cut for the CPU tests: 2 layers of width ``d``, grouped
+    queries kept (4 heads over 2 key/value heads), a gated MLP of 3d and a
+    vocabulary of 4d + 44, in the file's keys and the program's fields
+    alike."""
+    conf = dict(conf, num_hidden_layers=2, hidden_size=d,
+                num_attention_heads=4, num_key_value_heads=2,
+                intermediate_size=3 * d, vocab_size=4 * d + 44)
+    prog = conf["program"]
+    conf["program"] = dict(prog, fields=dict(
+        prog["fields"], n_layers=2, d_model=d, n_heads=4, n_kv_heads=2,
+        head_dim=d // 4, d_ff=3 * d, vocab_size=4 * d + 44))
+    return conf

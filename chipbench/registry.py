@@ -3,11 +3,31 @@
 A configuration is the JSON file its entry names; a traffic mix is
 ``traffic/<name>.json``; a per-layer metric's reader is
 ``metrics/<name>.py``; the limits of a cell's correctness check are
-``limits/<workload>.json``.  Adding a cell means adding files and entries,
-never editing this module.
+``limits/<workload>.json``; a Pallas kernel's operation count is
+``kernel_ops/<kernel function>.py``.  Adding a cell means adding files and
+entries, never editing a module that is there.
+
+What a configuration brings, all as new files and entries:
+
+* its JSON file (``configs/<name>.json``) with its entry in
+  ``BENCHMARK.json``: the keys of the published config as run, the name
+  of its ``reference``, the ``program`` architecture and fields, and
+  optionally ``scopes``, the extra component names its program sets with
+  ``jax.named_scope`` (``chipbench.scopes``);
+* its plain reference, ``reference/<reference>.py``: ``init(key, conf)``
+  (the layers' weights under ``blocks``), ``seq_loss(params, row, conf)``,
+  ``train_ops_per_token(conf, seq)`` (``chipbench.flops``' convention)
+  and ``tiny(conf, d)``, the configuration cut for the CPU tests;
+* per cell: its traffic mix, ``traffic/<name>.json``, and its limits,
+  ``limits/<workload>.json``;
+* one ``kernel_ops/<kernel>.py`` per new Pallas kernel: ``PRECISION``
+  (``bf16`` or ``int8``, whose peak bounds its operations) and
+  ``ops(call)``, its operations from a ``chipbench.trace.Call``'s shapes;
+* a reader ``metrics/<name>.py`` per new per-layer metric.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -66,17 +86,30 @@ def metrics_for(bench: Dict, workload_name: str, kind: str) -> List[Dict]:
             if workload_name in m.get("workloads", [workload_name])]
 
 
-def metric_reader(name: str):
-    """The module ``metrics/<name>.py``; its ``read(ctx)`` returns the
-    metric's value or None where the run holds nothing to read."""
-    path = HERE / "metrics" / f"{name}.py"
+@functools.lru_cache(maxsize=None)
+def _module(kind: str, name: str):
+    """The module ``<kind>/<name>.py``, loaded from its file once."""
+    path = HERE / kind / f"{name}.py"
     if not path.is_file():
-        raise BenchError(f"missing reader {path.relative_to(ROOT)}")
+        raise BenchError(f"missing file {path.relative_to(ROOT)}")
     spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_"),
+        path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str):
+    """The module ``metrics/<name>.py``; its ``read(ctx)`` returns the
+    metric's value or None where the run holds nothing to read."""
+    return _module("metrics", name)
+
+
+def kernel_ops(kernel: str):
+    """The module ``kernel_ops/<kernel>.py`` of a Pallas kernel function:
+    ``PRECISION`` and ``ops(call)``."""
+    return _module("kernel_ops", kernel)
 
 
 def reference(conf: Dict):

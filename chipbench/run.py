@@ -27,6 +27,8 @@ import sys  # noqa: E402
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 #: JAX's persistent compilation cache: a fixed directory inside the checkout
 CACHE_DIR = ROOT / ".jax_cache"
+#: entries of each list of the result line's ``breakdown``
+TOP = 10
 
 
 def parse_args(argv=None) -> argparse.Namespace:
@@ -68,6 +70,27 @@ def result_line(res: dict, metrics: dict, device: dict,
     return json.dumps(out)
 
 
+def read_trace(bench: dict, workload: str, ctx) -> tuple:
+    """(metrics, breakdown) of a traced window: the cell's per-layer
+    metrics that its readers find, and the breakdown: the reduction's
+    ``device_ops`` and ``idle_gaps`` and, where the readers split the
+    window by scope (``chipbench.scopes``), its ``device_scopes`` and
+    ``idle_before``, each as at most :data:`TOP` ``[name, seconds]``,
+    largest first."""
+    from chipbench import registry
+    metrics = {}
+    for m in registry.metrics_for(bench, workload, "per_layer"):
+        v = registry.metric_reader(m["name"]).read(ctx)
+        if v is not None:
+            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    breakdown = ctx.red.breakdown(TOP)
+    part = getattr(ctx, "_scopes", None)
+    if part is not None:
+        breakdown.update({k: [[n, v] for n, v in d.items()][:TOP]
+                          for k, d in part.breakdown().items()})
+    return metrics, breakdown
+
+
 def execute(args, bench: dict, cell: dict, conf: dict, mix: dict,
             lim: dict, devs, fault=None) -> str:
     """Everything of a run after the look for chips: set up, measure,
@@ -88,13 +111,8 @@ def execute(args, bench: dict, cell: dict, conf: dict, mix: dict,
         red = watch.reduce()
         device["busy_s"] = red.busy_s
         device["window_s"] = red.window_s
-        breakdown = red.breakdown()
         ctx = cbtrace.Context(red, conf, mix, res, devs[0].device_kind)
-        metrics = {}
-        for m in registry.metrics_for(bench, args.workload, "per_layer"):
-            v = registry.metric_reader(m["name"]).read(ctx)
-            if v is not None:
-                metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        metrics, breakdown = read_trace(bench, args.workload, ctx)
         watch.cleanup()
     else:
         setup_s = res["setup_end"] - _T0

@@ -19,7 +19,8 @@ Attribution of one executed instruction:
   instruction whose computation holds it (a loop's);
 * component: ``optimizer`` where the path holds that scope (the whole
   update is the optimizer's, its moment codec too), else the innermost
-  scope of :data:`COMPONENTS` on the path, matched as a whole name; an
+  scope of :data:`COMPONENTS` on the path, or of the extra names the
+  configuration lists under ``scopes``, matched as a whole name; an
   instruction with none is ``unattributed``;
 * phase: ``optimizer`` inside that scope; ``recompute`` where the path
   holds ``rematted_computation`` (JAX's name for work that
@@ -42,14 +43,18 @@ import dataclasses
 import json
 import re
 import sys
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+from chipbench import registry
 from chipbench import trace as cbtrace
 
 #: the program's scope names, one per component of the train step
 COMPONENTS = ("embed", "layers", "norm", "attention", "linear", "qdq",
               "head", "weights_cast", "optimizer")
 PHASES = ("forward", "backward", "recompute", "optimizer")
+#: names JAX itself puts on the path, which no configuration may claim
+JAX_NAMES = frozenset({"while", "body", "checkpoint", "rematted_computation",
+                       "jvp", "transpose"})
 UNATTRIBUTED = "unattributed"
 #: idle time with no program executing
 BETWEEN = "between programs"
@@ -93,13 +98,24 @@ def _names(path: str) -> List[str]:
     return out
 
 
-def classify(op_name: str) -> Tuple[str, str]:
-    """(component, phase) of one operation's name path."""
+def components(extra: Sequence[str] = ()) -> Tuple[str, ...]:
+    """:data:`COMPONENTS` and a configuration's extra scope names; a name
+    that JAX itself puts on paths is refused."""
+    taken = sorted(JAX_NAMES.intersection(extra))
+    if taken:
+        raise registry.BenchError(
+            f"scope names {taken} are JAX's own and name no component")
+    return COMPONENTS + tuple(n for n in extra if n not in COMPONENTS)
+
+
+def classify(op_name: str, extra: Sequence[str] = ()) -> Tuple[str, str]:
+    """(component, phase) of one operation's name path; ``extra`` are the
+    configuration's own component names (its ``scopes``)."""
     plain = _names(op_name)
     if "optimizer" in plain:
         return "optimizer", "optimizer"
-    comp = next((n for n in reversed(plain) if n in COMPONENTS),
-                UNATTRIBUTED)
+    comps = components(extra)
+    comp = next((n for n in reversed(plain) if n in comps), UNATTRIBUTED)
     if "rematted_computation" in plain:
         phase = "recompute"
     elif "transpose(" in op_name:
@@ -248,14 +264,15 @@ class Partition:
                 "idle_before": order(idle)}
 
 
-def partition(red: cbtrace.Reduction, program: Program,
-              top: int = 5) -> Partition:
-    """Split ``red``'s window by component and phase (module docstring)."""
+def partition(red: cbtrace.Reduction, program: Program, top: int = 5,
+              extra: Sequence[str] = ()) -> Partition:
+    """Split ``red``'s window by component and phase (module docstring);
+    ``extra`` as for :func:`classify`."""
     keys: Dict[str, Tuple[str, str]] = {}
 
     def key(name: str) -> Tuple[str, str]:
         if name not in keys:
-            keys[name] = classify(program.op_names.get(name, ""))
+            keys[name] = classify(program.op_names.get(name, ""), extra)
         return keys[name]
 
     device: Dict[Tuple[str, str], float] = {}
@@ -371,13 +388,14 @@ def _read(ctx: cbtrace.Context) -> Optional[Partition]:
                   "program the window ran; nothing read", file=sys.stderr,
                   flush=True)
         return None
-    part = partition(ctx.red, prog)
+    extra = ctx.conf.get("scopes", [])
+    part = partition(ctx.red, prog, extra=extra)
     report = dict(part.breakdown(), window_s=part.window_s,
                   top_unattributed=part.top_unattributed,
                   instructions=prog.instructions, fusions=prog.fusions,
                   custom_calls=prog.custom_calls,
                   ran_carried_names=any(
-                      classify(op)[0] != UNATTRIBUTED for op in
+                      classify(op, extra)[0] != UNATTRIBUTED for op in
                       read_program(ran).op_names.values()))
     print("chipbench.scopes " + json.dumps(report), file=sys.stderr,
           flush=True)

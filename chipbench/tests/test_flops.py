@@ -1,6 +1,5 @@
 """Operation counts against hand figures, and the peak table's rules."""
 import json
-import sys
 
 import pytest
 
@@ -8,28 +7,22 @@ from chipbench import flops, peaks, registry
 from chipbench.trace import Call
 
 
-@pytest.fixture(scope="module")
-def program_cfg():
-    sys.path.insert(0, str(registry.ROOT / "src"))
-    from chipbench.model import program_config
-    bench = registry.benchmark()
-    return lambda name: program_config(registry.config(bench, name))
+def _conf(name):
+    return registry.config(registry.benchmark(), name)
 
 
-def test_gpt2_small_ops_per_token(program_cfg):
-    # 6 x 85.0M block parameters + 6 x 768 x 50257 (tied head)
-    # + 12 x 12 x 1024 x 768 (attention) = 0.855 GFLOP
-    ops = flops.train_ops_per_token(program_cfg("gpt2-small"), 1024)
-    assert sum(ops.values()) == pytest.approx(0.86e9, rel=0.01)
-    assert ops["linears"] == pytest.approx(6 * 84.93e6, rel=1e-3)
+def test_gpt2_small_ops_per_token():
+    # 6 x 84,934,656 block weights; 6 x 768 x 50257 (tied head)
+    # + 12 x 12 x 1024 x 12 x 64 (attention)
+    ops = flops.train_ops_per_token(_conf("gpt2-small"), 1024)
+    assert ops == {"linears": 509_607_936, "other": 344_830_464}
 
 
-def test_yi_cut4_ops_per_token(program_cfg):
-    # 4.15 GFLOP in the block linears + 1.19 GFLOP (head, attention at 4096)
-    ops = flops.train_ops_per_token(program_cfg("yi-6b-cut4"), 4096)
-    assert ops["linears"] == pytest.approx(4.15e9, rel=0.01)
-    assert ops["other"] == pytest.approx(1.19e9, rel=0.01)
-    assert sum(ops.values()) == pytest.approx(5.3e9, rel=0.02)
+def test_yi_cut4_ops_per_token():
+    # 6 x 4 x 173,015,040 block weights; 6 x 4096 x 16000 (untied head)
+    # + 12 x 4 x 4096 x 32 x 128 (attention)
+    ops = flops.train_ops_per_token(_conf("yi-6b-cut4"), 4096)
+    assert ops == {"linears": 4_152_360_960, "other": 1_198_522_368}
 
 
 def test_unknown_device_kind_raises():
@@ -67,8 +60,16 @@ def test_matmul_ops_from_shapes():
     tn = Call("_int8_matmul_tn_kernel", (("f32", (128, 384)),),
               (("s8", (256, 128)), ("bf16", (256, 384))))
     for c in (fwd, nt, tn):
-        assert flops.KERNEL_OPS[c.kernel](c) == 2 * 256 * 128 * 384
+        assert registry.kernel_ops(c.kernel).ops(c) == 2 * 256 * 128 * 384
     assert fwd.nbytes == 256 * 128 + 128 * 384 + 2 * 256 * 384
+
+
+def test_unknown_kernel_names_its_missing_file():
+    call = Call("_no_such_kernel", (("bf16", (8, 128)),),
+                (("s8", (8, 128)), ("s8", (128, 128))))
+    with pytest.raises(registry.BenchError,
+                       match="kernel_ops/_no_such_kernel.py"):
+        flops.kernel_least_time(call, "TPU v5 lite")
 
 
 def test_configs_are_json_with_their_source():

@@ -12,12 +12,10 @@ older outputs to what they were before the names came, on the older
 """
 import pathlib
 import re
-import types
 
 import pytest
 
-from chipbench import model as cbmodel
-from chipbench import registry, scopes, train
+from chipbench import registry, run, scopes, train
 from chipbench import trace as cbtrace
 from chipbench.tests import tiny
 
@@ -169,8 +167,7 @@ def test_older_outputs_unchanged():
     bench = registry.benchmark()
     conf = registry.config(bench, "gpt2-small")
     mix = registry.traffic("pretrain-int8-s1024")
-    res = {"cell": types.SimpleNamespace(cfg=cbmodel.program_config(conf)),
-           "window": {"tokens": 512}}
+    res = {"window": {"tokens": 512}}
     ctx = cbtrace.Context(red, conf, mix, res, "TPU v5 lite")
     got = {n: registry.metric_reader(n).read(ctx) for n in (
         "device_idle_share.train", "step_mfu.train",
@@ -179,3 +176,43 @@ def test_older_outputs_unchanged():
                    "step_mfu.train": 2.399364209070837,
                    "int8_matmul_roofline.train": 8.367032064799666,
                    "adamw_roofline.train": None}
+
+
+def test_result_line_carries_the_component_shares(red, part):
+    """The traced run's ``breakdown`` adds the readers' partition to the
+    reduction's lists, in their form: at most ten ``[name, seconds]``,
+    largest first."""
+    bench = registry.benchmark()
+    ctx = cbtrace.Context(red, registry.config(bench, "gpt2-small"),
+                          registry.traffic("pretrain-int8-s1024"),
+                          {"window": {"tokens": 512}}, "TPU v5 lite")
+    metrics, bd = run.read_trace(bench, "gpt2s-pretrain-int8", ctx)
+    assert "qdq_share.train" in metrics and "step_mfu.train" in metrics
+    assert list(bd) == ["device_ops", "idle_gaps", "device_scopes",
+                        "idle_before"]
+    assert bd["device_ops"] == red.breakdown()["device_ops"]
+    want = part.breakdown()
+    for key in ("device_scopes", "idle_before"):
+        assert bd[key] == [[k, v] for k, v in want[key].items()][:10]
+    assert bd["device_scopes"][0] == ["linear/backward",
+                                      pytest.approx(693.36e-6, abs=1e-12)]
+    # a context whose readers split nothing adds nothing
+    serve = cbtrace.Context(red, {}, {"kind": "serve"}, {}, "TPU v5 lite")
+    assert list(run.read_trace(bench, "gpt2s-pretrain-int8", serve)[1]) == \
+        ["device_ops", "idle_gaps"]
+
+
+@pytest.mark.parametrize("extra", [["while"], ["router", "transpose"],
+                                   ["rematted_computation"]])
+def test_jax_names_are_refused_as_components(extra):
+    with pytest.raises(registry.BenchError, match="JAX's own"):
+        scopes.classify("jit(f)/layers/router/dot", extra)
+
+
+def test_configuration_names_join_the_components():
+    path = "jit(f)/jvp(layers)/while/body/router/dot"
+    assert scopes.classify(path) == ("layers", "forward")
+    assert scopes.classify(path, ["router"]) == ("router", "forward")
+    # the innermost name wins, whichever list it comes from
+    assert scopes.classify(path + "/linear/qdq", ["router"]) == \
+        ("qdq", "forward")

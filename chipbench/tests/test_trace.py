@@ -14,7 +14,7 @@ import pathlib
 
 import pytest
 
-from chipbench import flops, peaks
+from chipbench import flops, peaks, registry
 from chipbench import trace as cbtrace
 
 DATA = pathlib.Path(__file__).parent / "data"
@@ -34,7 +34,7 @@ def test_kernel_named_from_the_compiled_text():
     c = kernels[0]
     assert c.operands[:2] == (("s8", (512, 512)), ("s8", (512, 256)))
     assert c.outputs == (("bf16", (512, 256)),)
-    assert flops.KERNEL_OPS[c.kernel](c) == 2 * 512 * 512 * 256
+    assert registry.kernel_ops(c.kernel).ops(c) == 2 * 512 * 512 * 256
 
 
 def test_window_and_busy_time(red):

@@ -7,23 +7,15 @@ import argparse
 
 from chipbench import registry
 
-def _gpt2(bench, d: int):
-    conf = registry.config(bench, "gpt2-small")
-    conf.update(n_layer=2, n_embd=d, n_head=2, n_positions=64, n_ctx=64,
-                vocab_size=4 * d + 44)
-    conf["program"]["fields"].update(
-        n_layers=2, d_model=d, n_heads=2, n_kv_heads=2, head_dim=d // 2,
-        d_ff=4 * d, vocab_size=4 * d + 44, max_seq=64)
-    return conf
-
 
 def cell(workload: str, d: int = 64):
-    """(bench, cell, conf, mix, lim) of the gpt2-small training cell
-    ``workload`` at a tiny size: 2 layers of width ``d``, batch 4 (8 at
-    width 128 and more) of 32 (64) tokens."""
+    """(bench, cell, conf, mix, lim) of the training cell ``workload`` at a
+    tiny size: its configuration cut by its reference's ``tiny`` to width
+    ``d``, batch 4 (8 at width 128 and more) of 32 (64) tokens."""
     bench = registry.benchmark()
     cell = registry.workload(bench, workload)
-    conf = _gpt2(bench, d)
+    conf = registry.config(bench, cell["config"])
+    conf = registry.reference(conf).tiny(conf, d)
     mix = registry.traffic(cell["traffic"])
     mix.update(batch=4, seq=32, pool=4) if d < 128 else \
         mix.update(batch=8, seq=64, pool=4)
